@@ -31,11 +31,11 @@ import jax
 
 from repro.configs.base import ARCHITECTURES, SHAPES, get_config
 from repro.models.model import count_params
-from repro.obs.prof import HW_MODELS, LINK_BW, analyze_hlo
+from repro.obs.prof import LINK_BW, PEAKS, analyze_hlo
 
 # hardware model now lives in repro.obs.prof (shared with the runtime
 # roofline gauges); this table is always priced for the TPU part.
-PEAK_FLOPS, HBM_BW = HW_MODELS["tpu"]
+PEAK_FLOPS, HBM_BW = PEAKS["TPU v5 lite"]
 
 DRYRUN_DIR = Path(__file__).resolve().parent.parent / "results" / "dryrun"
 

@@ -116,14 +116,22 @@ def sharded_coverage(shard_bits: int, num_shards: int, n: int, lo, hi,
     1.0 for fully-covered (or empty) queries; broadcasts over batches.
     The explicit honesty signal degraded-mode answers ship with.
     """
+    return _coverage_parts(shard_bits, num_shards, n, lo, hi, available)[2]
+
+
+def _coverage_parts(shard_bits: int, num_shards: int, n: int, lo, hi,
+                    available):
+    """(total, covered, coverage) positions of [lo, hi). Coverage is
+    exactly 1.0 whenever every position is covered: the TPU's float
+    division is not exact, so x / x need not be 1."""
     los, his = local_ranges(shard_bits, num_shards, n, lo, hi)
     total = jnp.sum(his - los, axis=0)
     _, mhis = mask_ranges(los, his, available)
     covered = jnp.sum(mhis - los, axis=0)
-    return jnp.where(total > 0,
-                     covered.astype(jnp.float32)
-                     / jnp.maximum(total, 1).astype(jnp.float32),
-                     jnp.float32(1.0))
+    frac = covered.astype(jnp.float32) / jnp.maximum(total, 1).astype(
+        jnp.float32)
+    return total, covered, jnp.where(covered == total, jnp.float32(1.0),
+                                     frac)
 
 
 def sharded_range_count_bounds(shards: WaveletMatrix, shard_bits: int,
@@ -138,14 +146,8 @@ def sharded_range_count_bounds(shards: WaveletMatrix, shard_bits: int,
     S = _num_shards(shards)
     lower = sharded_range_count(shards, shard_bits, n, lo, hi,
                                 sym_lo, sym_hi, available)
-    los, his = local_ranges(shard_bits, S, n, lo, hi)
-    total = jnp.sum(his - los, axis=0)
-    _, mhis = mask_ranges(los, his, available)
-    covered = jnp.sum(mhis - los, axis=0)
-    cov = jnp.where(total > 0,
-                    covered.astype(jnp.float32)
-                    / jnp.maximum(total, 1).astype(jnp.float32),
-                    jnp.float32(1.0))
+    total, covered, cov = _coverage_parts(shard_bits, S, n, lo, hi,
+                                          available)
     return lower, lower + (total - covered), cov
 
 
@@ -311,14 +313,8 @@ def sharded_range_histogram_bounds(shards: WaveletMatrix, shard_bits: int,
     [hist_lower[c], hist_lower[c] + uncovered]."""
     S = _num_shards(shards)
     hist = sharded_range_histogram(shards, shard_bits, n, lo, hi, available)
-    los, his = local_ranges(shard_bits, S, n, lo, hi)
-    total = jnp.sum(his - los, axis=0)
-    _, mhis = mask_ranges(los, his, available)
-    covered = jnp.sum(mhis - los, axis=0)
-    cov = jnp.where(total > 0,
-                    covered.astype(jnp.float32)
-                    / jnp.maximum(total, 1).astype(jnp.float32),
-                    jnp.float32(1.0))
+    total, covered, cov = _coverage_parts(shard_bits, S, n, lo, hi,
+                                          available)
     return hist, total - covered, cov
 
 
@@ -327,6 +323,16 @@ def sharded_range_distinct(shards: WaveletMatrix, shard_bits: int, n: int,
     """# of distinct symbols in global [lo, hi) (union across shards)."""
     hist = sharded_range_histogram(shards, shard_bits, n, lo, hi, available)
     return jnp.sum(hist > 0, axis=-1).astype(_I32)
+
+
+@jax.jit
+def _probe_count(shards: WaveletMatrix, s: jax.Array,
+                 sigma: jax.Array) -> jax.Array:
+    """One-position count on shard ``s`` — the liveness probe, compiled
+    once for every shard index (one dispatch per probe)."""
+    zero = jnp.asarray(0, _I32)
+    return range_ops.range_count(_shard(shards, s), zero,
+                                 jnp.asarray(1, _I32), zero, sigma)
 
 
 # --------------------------------------------------------------------------
@@ -490,11 +496,8 @@ class ShardedAnalytics:
         delay = shard_latency(s)
         if delay > 0:
             clock.sleep(delay)
-        wm = self.shard(int(s))
-        out = range_ops.range_count(wm, jnp.asarray(0, _I32),
-                                    jnp.asarray(1, _I32),
-                                    jnp.asarray(0, _I32),
-                                    jnp.asarray(self.sigma, _I32))
+        out = _probe_count(self.shards, jnp.asarray(s, _I32),
+                           jnp.asarray(self.sigma, _I32))
         return bool(jax.block_until_ready(out) >= 0)
 
     def range_count(self, lo, hi, sym_lo, sym_hi) -> jax.Array:

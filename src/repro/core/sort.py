@@ -12,10 +12,9 @@ provide the two TPU-native realizations (both stable):
   vectorizes over the whole array. For wide digits the stable rank runs
   blocked (per-block histogram → cross-block scan → within-block rank, the
   same block-local-count-then-scan structure as the paper's domain-
-  decomposition merge): on TPU through the Pallas ``kernels.radix_rank``
-  kernel (the one-hot never leaves VMEM), elsewhere through an XLA
-  realization that vectorizes groups of blocks under a bounded one-hot
-  working set.
+  decomposition merge) through an XLA realization that vectorizes groups
+  of blocks under a bounded one-hot working set. The Pallas
+  ``kernels.radix_rank`` form is opt-in (``use_kernel=True``).
 * ``backend="xla"`` — ``jax.lax.sort`` (stable), the vendor-shipped sort.
 
 Both are benchmarked against each other in ``benchmarks/run.py``. The
@@ -112,25 +111,22 @@ def _blocked_rank_parts(digits: jax.Array, num_buckets: int,
 
 
 def counting_rank(digits: jax.Array, num_buckets: int,
-                  use_kernel: bool | None = None) -> jax.Array:
+                  use_kernel: bool = False) -> jax.Array:
     """Stable sort destinations (a permutation when there is no padding).
 
     dest[i] = (# elements with smaller digit) + (# j<i with equal digit) —
     the paper's "stable integer sort via prefix sums" (Section 2), used as
     the big-node sort of Theorem 4.5 and by every suffix-array doubling
     round. Routing: small bucket counts use the fully vectorized one-hot;
-    large ones the blocked histogram→scan→within-block path — through the
-    Pallas ``kernels.radix_rank`` kernel when ``use_kernel`` (default: on
-    TPU) and the bucket count fits its VMEM bound, else the XLA blocked
-    realization.
+    large ones the blocked histogram→scan→within-block path, in XLA.
+    ``use_kernel=True`` routes that path through the Pallas
+    ``kernels.radix_rank`` kernel when the bucket count fits its VMEM bound;
+    it is off by default because the kernel does not compile for a TPU
+    v5e (its (1, B+1) histogram blocks are not (8,128)-aligned).
     """
     n = digits.shape[0]
     if num_buckets <= _VECTORIZED_BUCKET_LIMIT or n <= 4 * _BLOCK:
         return _counting_rank_vectorized(digits, num_buckets)
-    if use_kernel is None:
-        # the radix_rank kernels are stateless (no cross-grid scratch), so
-        # the route is safe under jit/vmap and gates on the backend alone
-        use_kernel = jax.default_backend() == "tpu"
     if use_kernel:
         from repro.kernels import ops as _kops
         from repro.kernels import radix_rank as _rr

@@ -23,13 +23,14 @@ Theorem 5.1 word-rank/select directory, built per level in O(n/log n)
 work, answers "which element lands at position p"), the composed
 permutation advances only when a compose big step will consume it, and all
 ``nbits`` rank/select directories are built as one batched launch group
-(``rank_select.build_bitvector_levels``). On TPU the per-level step and
-the batched rank tables can further route through the Pallas kernels
-``kernels.wm_level`` (bit extract + bitmap pack + zero count + stable
-destinations in a single launch over the narrow short list) and
-``kernels.rank_build`` (all levels' Jacobson tables in one launch); the
-big-step counting sort routes through ``kernels.radix_rank`` via
-``core.sort.counting_rank``. Outputs are bit-identical on every path.
+(``rank_select.build_bitvector_levels``). With ``use_kernels=True`` the
+per-level step and the batched rank tables route through the Pallas
+kernels ``kernels.wm_level`` (bit extract + bitmap pack + zero count +
+stable destinations in a single launch over the narrow short list) and
+``kernels.rank_build`` (all levels' Jacobson tables in one launch). That
+route is opt-in: the kernels do not compile for a TPU v5e yet, so every
+backend builds through XLA by default. Outputs are bit-identical on every
+path.
 """
 from __future__ import annotations
 
@@ -63,24 +64,6 @@ def reverse_bits(x: jax.Array, width: int) -> jax.Array:
     return out
 
 
-def default_use_kernels(seq: jax.Array) -> bool:
-    """Default Pallas-kernel routing for the fused builders: auto on TPU,
-    mechanically off when the builder sees a batching tracer (the fused
-    level kernels carry cross-grid scratch, so they must not be vmapped).
-    The guard cannot see through ``vmap``-of-``jit`` composition — callers
-    wrapping a *jitted* builder in ``vmap`` on TPU must pass
-    ``use_kernels=False`` themselves. Guard trips are counted
-    (``core.kernel_guard_trip``) so profile runs show when shard builds
-    silently lose the kernels."""
-    from jax.interpreters import batching
-    from repro import obs
-    if isinstance(seq, batching.BatchTracer):
-        if jax.default_backend() == "tpu":
-            obs.counter("core.kernel_guard_trip", reason="batch_tracer").inc()
-        return False
-    return jax.default_backend() == "tpu"
-
-
 @jax.tree_util.register_dataclass
 @dataclass(frozen=True)
 class WaveletMatrix:
@@ -102,7 +85,7 @@ def build_wavelet_matrix(seq: jax.Array, sigma: int, tau: int = 8,
                          big_step: str = "compose",
                          sample_rate: int = 512,
                          fused: bool = True,
-                         use_kernels: bool | None = None) -> WaveletMatrix:
+                         use_kernels: bool = False) -> WaveletMatrix:
     """τ-chunked parallel construction (paper Theorem 4.5).
 
     ``tau`` plays the paper's τ = √(log n) role; 8 (byte-aligned) is the TPU
@@ -120,22 +103,17 @@ def build_wavelet_matrix(seq: jax.Array, sigma: int, tau: int = 8,
     step-by-step path (scatter-based inverse permutations, per-level
     directory builds) kept as the benchmark baseline.
 
-    ``use_kernels`` routes the per-level step and the batched rank tables
-    through the Pallas kernels (``kernels.wm_level`` /
-    ``kernels.rank_build``); ``None`` auto-enables them on TPU. Those two
-    kernels carry cross-grid scratch state, so they must not be batched:
-    the ``None`` default disables them when the builder sees a batching
-    tracer as input (direct ``vmap``, as in the shard builds). The guard
-    cannot see through ``vmap``-of-``jit`` composition — callers wrapping
-    a *jitted* builder in ``vmap`` on TPU must pass ``use_kernels=False``
-    themselves. Passing ``use_kernels=True`` overrides the guard.
+    ``use_kernels=True`` routes the per-level step and the batched rank
+    tables through the Pallas kernels (``kernels.wm_level`` /
+    ``kernels.rank_build``). It is off by default on every backend: neither
+    kernel compiles for a TPU v5e (block shapes not (8,128)-aligned), so
+    they run only in interpret mode, as a reference for the tests. They
+    carry cross-grid scratch state and must not be batched.
 
     Output is bit-identical across ``fused``/``use_kernels``/``big_step``
     settings (and to ``build_wavelet_matrix_levelwise``).
     """
     from repro import obs
-    if use_kernels is None:
-        use_kernels = default_use_kernels(seq)
     obs.counter("core.build", builder="wm",
                 path="fused" if fused else "scatter").inc()
     if not fused:

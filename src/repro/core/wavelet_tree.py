@@ -35,7 +35,7 @@ from .rank_select import (BitVector, access_bit, build_bitvector,
 from .scan import (apply_permutation_dest, exclusive_sum,
                    segment_ids_from_starts, segmented_exclusive_sum)
 from .sort import _invert_permutation, counting_rank, sort_pass
-from .wavelet_matrix import default_use_kernels, num_levels
+from .wavelet_matrix import num_levels
 
 _U32 = jnp.uint32
 _I32 = jnp.int32
@@ -140,7 +140,7 @@ def build_wavelet_tree(seq: jax.Array, sigma: int, tau: int = 8,
                        big_step: str = "compose",
                        sample_rate: int = 512,
                        fused: bool = True,
-                       use_kernels: bool | None = None) -> WaveletTree:
+                       use_kernels: bool = False) -> WaveletTree:
     """τ-chunked sort-based construction (paper Theorem 4.1).
 
     ``fused=True`` (default) is the segmented select-gather fast path:
@@ -157,13 +157,11 @@ def build_wavelet_tree(seq: jax.Array, sigma: int, tau: int = 8,
 
     ``use_kernels`` routes shallow levels (2^(l+1) key buckets within the
     ``kernels.wt_level`` VMEM bound) through the fused Pallas segmented
-    level step and the directory builds through ``kernels.rank_build``;
-    ``None`` auto-enables on TPU with the same BatchTracer guard as
-    ``build_wavelet_matrix``.
+    level step and the directory builds through ``kernels.rank_build``.
+    Off by default on every backend: neither kernel compiles for a TPU
+    v5e, so they run only in interpret mode (see ``build_wavelet_matrix``).
     """
     from repro import obs
-    if use_kernels is None:
-        use_kernels = default_use_kernels(seq)
     obs.counter("core.build", builder="wt",
                 path="fused" if fused else "scatter").inc()
     if not fused:

@@ -171,8 +171,6 @@ def build_compressed_corpus(tokens: np.ndarray, sigma: int,
         toks = np.concatenate([toks, np.zeros(pad, np.uint32)])
     shards_np = toks.reshape(num_shards, shard_size)
 
-    # The builder picks its own kernel route: Pallas on TPU, mechanically
-    # falling back to the (fully batchable) XLA fast path when vmapped.
     # jit_loop compiles the whole builder once on the sequential path so
     # every shard reuses one executable.
     stacked = build_shards_stacked(

@@ -97,9 +97,6 @@ def build_fm_index(seq, sigma: int, *, sample_rate: int = 32,
     bwt, sa, C = bwt_encode(seq, sigma, backend=backend)
     m = int(bwt.shape[0])
     sigma_work = sigma + SENTINEL_SHIFT
-    # The builder picks its own kernel route (Pallas on TPU, mechanically
-    # falling back to the batchable XLA fast path under vmapped shard
-    # builds — see build_wavelet_matrix's use_kernels guard).
     wm = build_wavelet_matrix(bwt, sigma_work, tau=tau, big_step=big_step,
                               sample_rate=bv_sample_rate)
 

@@ -101,7 +101,10 @@ class ShardedTextIndex:
         if self.available is None:
             return jnp.float32(1.0)
         covered = jnp.sum(jnp.where(self.available, self._shard_sizes(), 0))
-        return covered.astype(jnp.float32) / jnp.float32(max(1, self.n))
+        # exact 1.0 when all is covered: TPU float division is not exact
+        return jnp.where(covered == self.n, jnp.float32(1.0),
+                         covered.astype(jnp.float32)
+                         / jnp.float32(max(1, self.n)))
 
     def shard(self, s: jax.Array) -> FMIndex:
         return jax.tree.map(lambda l: l[s], self.shards)

@@ -2,7 +2,13 @@
 
 Each op handles padding/layout, dispatches to the kernel, and trims the
 result. ``interpret`` defaults to True off-TPU so the same call sites work
-on CPU (validation) and TPU (deployment).
+on CPU (validation) and TPU.
+
+None of these kernels compiles for a TPU v5e yet: their block shapes are
+not (8,128)-aligned (``bitpack`` also reduces over unsigned integers and
+``wm_quantile`` needs a non-2D gather). So no default path calls them;
+each is reached only through an explicit ``use_kernels``/``use_kernel``
+option, and the tests run them in interpret mode against the XLA paths.
 """
 from __future__ import annotations
 

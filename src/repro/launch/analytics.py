@@ -35,7 +35,8 @@ from repro import obs
 from repro.analytics import (build_sharded_analytics, load_analytics,
                              save_analytics, snapshot_meta)
 from repro.data import make_corpus
-from repro.launch.mesh import make_host_mesh, set_mesh
+from repro.launch.compile_cache import enable_compile_cache
+from repro.launch.mesh import make_host_mesh
 
 
 def make_queries(n: int, num: int, seed: int):
@@ -51,6 +52,7 @@ def make_queries(n: int, num: int, seed: int):
 
 
 def main():
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--smoke", action="store_true",
                     help="CI-sized build + query + verification")
@@ -150,9 +152,9 @@ def main():
     B = args.queries
     obs.gauge("serve.analytics.coverage").set(float(eng.coverage(0, args.n)))
 
-    # cost-model profile of the construction path (one shard-sized build)
-    # and the Pallas kernel descent, so the snapshot relates build/serve
-    # time to the hardware roofline alongside the serving ops below
+    # cost-model profile of the construction path (one shard-sized build),
+    # so the snapshot relates build time to the hardware roofline
+    # alongside the serving ops below
     from repro.core.wavelet_matrix import build_wavelet_matrix
     shard0 = jnp.asarray(toks[:eng.shard_size], jnp.int32)
     _, cstats = obs.profile_op(
@@ -162,15 +164,8 @@ def main():
     if "roofline_util" in cstats:
         print(f"construct_shard: roofline {cstats['roofline_util']:.1%} "
               f"({cstats.get('bound', '?')}-bound)")
-    nk = min(16, B)
-    _, kstats = obs.profile_op(
-        "analytics.quantile_kernel",
-        lambda e, a, b, c: e.range_quantile(a, b, c, use_kernel=True),
-        eng, loj[:nk], hij[:nk], kj[:nk], work_elements=float(nk))
-    if "error" in kstats:
-        print(f"quantile_kernel profile skipped: {kstats['error']}")
 
-    mesh_ctx = set_mesh(make_host_mesh())
+    mesh_ctx = jax.set_mesh(make_host_mesh())
     with mesh_ctx, obs.span("analytics.serve", queries=B), \
             obs.trace(args.profile_dir):
         ops = {

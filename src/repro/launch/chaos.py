@@ -61,6 +61,7 @@ from repro.analytics import load_analytics, save_analytics
 from repro.analytics.engine import build_sharded_analytics
 from repro.data import make_corpus
 from repro.index import build_sharded_index
+from repro.launch.compile_cache import enable_compile_cache
 from repro.robust import (IntegrityError, corrupt_snapshot_leaf, delete_file,
                           flip_leaf_bit, inject_partial_tmp,
                           repair_analytics, repair_sharded_index,
@@ -577,6 +578,7 @@ def run_overload_scenarios(seed: int, check: Check):
 
 
 def main():
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--smoke", action="store_true",
                     help="CI-sized run (sizes are already small)")

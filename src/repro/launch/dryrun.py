@@ -19,8 +19,8 @@ from jax.sharding import NamedSharding, PartitionSpec as P  # noqa: E402
 from repro import obs    # noqa: E402
 from repro.configs.base import (ARCHITECTURES, SHAPES, get_config,  # noqa: E402
                                 supports_shape)
-from repro.launch.mesh import (dp_axes, make_production_mesh,  # noqa: E402
-                               set_mesh)
+from repro.launch.compile_cache import enable_compile_cache  # noqa: E402
+from repro.launch.mesh import dp_axes, make_production_mesh  # noqa: E402
 from repro.models.model import (abstract_cache, abstract_params,  # noqa: E402
                                 build_model, cache_specs, param_specs)
 from repro.optim.adamw import abstract_opt_state, adamw_update  # noqa: E402
@@ -231,7 +231,7 @@ def run_cell(arch: str, shape_name: str, multi_pod: bool,
     specs = input_specs(cfg, shape)
 
     sw = obs.Stopwatch()
-    mesh_ctx = set_mesh(mesh)
+    mesh_ctx = jax.set_mesh(mesh)
     mesh_ctx.__enter__()
     if shape.kind == "train":
         step = make_train_step(model, grad_accum=GRAD_ACCUM.get(arch, 1))
@@ -314,8 +314,6 @@ def _analyze_compiled(lowered, save_hlo: Path | None = None) -> dict:
     t_compile = sw.lap()
     mem = compiled.memory_analysis()
     cost = compiled.cost_analysis()
-    if isinstance(cost, (list, tuple)):    # older jax returns [dict]
-        cost = cost[0] if cost else {}
     hlo = compiled.as_text()
     if save_hlo:
         save_hlo.write_text(hlo)
@@ -387,6 +385,7 @@ def cell_id(arch, shape_name, multi_pod):
 
 
 def main():
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default=None)
     ap.add_argument("--shape", default=None)

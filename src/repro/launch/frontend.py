@@ -38,6 +38,7 @@ from repro import obs
 from repro.analytics.engine import build_sharded_analytics
 from repro.data import make_corpus
 from repro.ingest.serving import GenerationServer
+from repro.launch.compile_cache import enable_compile_cache
 from repro.serving import (BreakerConfig, FrontendConfig, QueryFrontend,
                            ShedError)
 
@@ -129,6 +130,7 @@ def report(fe: QueryFrontend, tickets: list, sw: obs.Stopwatch) -> dict:
 
 
 def main():
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--smoke", action="store_true",
                     help="CI-sized corpus + short trace")

@@ -25,6 +25,7 @@ import numpy as np
 from repro import obs
 from repro.data import make_corpus
 from repro.index import build_sharded_index, sample_patterns
+from repro.launch.compile_cache import enable_compile_cache
 
 
 def naive_count(toks: np.ndarray, pat: np.ndarray, plen: int,
@@ -80,6 +81,7 @@ def naive_count_degraded(toks: np.ndarray, pat: np.ndarray, plen: int,
 
 
 def main():
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--smoke", action="store_true",
                     help="CI-sized build + query + verification")

@@ -26,15 +26,3 @@ def dp_axes(mesh) -> tuple:
 def make_host_mesh():
     """Single-device mesh for CPU smoke runs (axes exist, size 1)."""
     return jax.make_mesh((1, 1), ("data", "model"))
-
-
-def set_mesh(mesh):
-    """Context manager installing ``mesh`` as the ambient mesh.
-
-    ``jax.set_mesh`` where it exists (jax ≥ 0.6); on older releases the
-    Mesh object itself is the context manager that sets the thread-local
-    physical mesh, which is all the jit/sharding paths here need.
-    """
-    if hasattr(jax, "set_mesh"):
-        return jax.set_mesh(mesh)
-    return mesh

@@ -23,6 +23,7 @@ import numpy as np
 from repro import obs
 from repro.configs.base import get_config
 from repro.data import make_corpus
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models.model import build_model, zero_cache
 
 
@@ -53,6 +54,7 @@ def _params_with_checkpoint(model, seed: int, ckpt_dir: str | None):
 
 
 def main():
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="qwen2_0_5b")
     ap.add_argument("--smoke", action="store_true")

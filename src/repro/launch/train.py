@@ -16,13 +16,15 @@ from jax.sharding import NamedSharding, PartitionSpec as P
 
 from repro.configs.base import get_config
 from repro.data import TokenBatcher, build_compressed_corpus, make_corpus
-from repro.launch.mesh import dp_axes, make_host_mesh, set_mesh
+from repro.launch.compile_cache import enable_compile_cache
+from repro.launch.mesh import dp_axes, make_host_mesh
 from repro.models import shard_ctx
 from repro.models.model import build_model, param_specs
 from repro.train import Trainer
 
 
 def main():
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="qwen2_0_5b")
     ap.add_argument("--smoke", action="store_true",
@@ -66,7 +68,7 @@ def main():
         mesh = make_host_mesh()
         sizes = dict(zip(mesh.axis_names, mesh.devices.shape))
         shard_ctx.set_mesh_context(dp_axes(mesh), sizes)
-        ctx = set_mesh(mesh)
+        ctx = jax.set_mesh(mesh)
         ctx.__enter__()
 
     trainer = Trainer(

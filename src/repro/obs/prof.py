@@ -23,9 +23,10 @@ Three ingredients:
   achieved FLOP/s and B/s, and ``prof.roofline_util`` = (cost-model bound
   time) / (measured time) — 1.0 means the op runs as fast as the hardware
   model allows, ≪1 means there is headroom the kernels are leaving on the
-  table. The per-backend hardware model is deliberately coarse
-  (documented constants, env-overridable) — utilization is a *trend*
-  metric for the regression sentry, not a certificate.
+  table. The hardware model is deliberately coarse (published peaks,
+  env-overridable) — utilization is a *trend*
+  metric for the regression sentry, not a certificate. Peaks are keyed by
+  the device's ``device_kind``; a kind without a published entry raises.
 * **Memory gauges** — ``record_memory_gauges`` snapshots
   ``jax.live_arrays()`` (count + bytes actually held alive) and, where
   the backend exposes it, ``device.memory_stats()`` peak/in-use bytes.
@@ -46,20 +47,25 @@ import re
 from typing import Dict, List, Optional, Tuple
 
 from .metrics import counter, gauge
-from .timing import Stopwatch, time_compiled, timed_op, track_shapes
+from .timing import Stopwatch, time_compiled, track_shapes
 
 # ---------------------------------------------------------------------------
 # hardware model
 # ---------------------------------------------------------------------------
 
-#: per-backend (peak FLOP/s, HBM bandwidth B/s) per device. TPU row is the
-#: v5e-class part the dryrun roofline always used (197 TFLOP/s bf16,
-#: 819 GB/s HBM); GPU is an A100-class placeholder; CPU is an
-#: order-of-magnitude container estimate (a few AVX cores + DDR). Override
-#: with REPRO_PEAK_FLOPS / REPRO_HBM_BW when you know your part.
-HW_MODELS: Dict[str, Tuple[float, float]] = {
-    "tpu": (197e12, 819e9),
-    "gpu": (312e12, 2.0e12),
+#: (peak FLOP/s, HBM bandwidth B/s) of one device, keyed by the
+#: ``device_kind`` JAX reports for it:
+#:
+#: * ``"TPU v5 lite"`` (TPU v5e) — Google Cloud documentation, "TPU v5e":
+#:   197 TFLOP/s bf16, 819 GB/s HBM.
+#: * ``"cpu"`` — an order-of-magnitude estimate for development runs (a
+#:   few AVX cores + DDR), not a published peak. A roofline share computed
+#:   against it is never a device metric.
+#:
+#: A kind missing here is an error, not a default: add it with its source.
+#: ``REPRO_PEAK_FLOPS`` / ``REPRO_HBM_BW`` override the looked-up values.
+PEAKS: Dict[str, Tuple[float, float]] = {
+    "TPU v5 lite": (197e12, 819e9),
     "cpu": (2.0e11, 5.0e10),
 }
 
@@ -68,13 +74,18 @@ HW_MODELS: Dict[str, Tuple[float, float]] = {
 LINK_BW = 50e9
 
 
-def hw_model(backend: str | None = None) -> Tuple[float, float]:
-    """(peak FLOP/s, HBM B/s) for ``backend`` (default: the jax backend),
-    with ``REPRO_PEAK_FLOPS`` / ``REPRO_HBM_BW`` env overrides."""
-    if backend is None:
+def hw_model(device_kind: str | None = None) -> Tuple[float, float]:
+    """(peak FLOP/s, HBM B/s) for ``device_kind`` (default: the kind of
+    ``jax.devices()[0]``), with ``REPRO_PEAK_FLOPS`` / ``REPRO_HBM_BW`` env
+    overrides. Raises ``KeyError`` for a kind ``PEAKS`` does not list."""
+    if device_kind is None:
         import jax
-        backend = jax.default_backend()
-    peak, bw = HW_MODELS.get(backend, HW_MODELS["cpu"])
+        device_kind = jax.devices()[0].device_kind
+    if device_kind not in PEAKS:
+        raise KeyError(f"no published peaks for device kind "
+                       f"{device_kind!r}; add it to repro.obs.prof.PEAKS "
+                       f"with its source")
+    peak, bw = PEAKS[device_kind]
     peak = float(os.environ.get("REPRO_PEAK_FLOPS", peak))
     bw = float(os.environ.get("REPRO_HBM_BW", bw))
     return peak, bw
@@ -85,21 +96,12 @@ def hw_model(backend: str | None = None) -> Tuple[float, float]:
 # ---------------------------------------------------------------------------
 
 def compiled_cost(compiled) -> Dict[str, float]:
-    """FLOPs / bytes-accessed from ``compiled.cost_analysis()``.
-
-    Handles both the list-of-dicts (older jax) and flat-dict forms;
-    returns {} when the backend exposes no cost model. NOTE: while-loop
+    """FLOPs / bytes-accessed from ``compiled.cost_analysis()`` (a flat
+    dict; {} when the backend exposes no cost model). NOTE: while-loop
     bodies are counted once — for loop-heavy programs prefer
     ``analyze_hlo`` on ``compiled.as_text()``.
     """
-    try:
-        ca = compiled.cost_analysis()
-    except Exception:                                         # noqa: BLE001
-        return {}
-    if isinstance(ca, (list, tuple)):
-        ca = ca[0] if ca else {}
-    if not isinstance(ca, dict):
-        return {}
+    ca = compiled.cost_analysis() or {}
     out = {}
     for key, name in (("flops", "flops"),
                       ("bytes accessed", "bytes_accessed"),
@@ -228,7 +230,7 @@ def _aot(fn, *args):
 
 
 def profile_op(name: str, fn, *args, iters: int = 1,
-               work_elements: Optional[float] = None, strict: bool = False):
+               work_elements: Optional[float] = None):
     """Compile ``fn(*args)`` ahead-of-time, read its HLO cost model, time
     steady-state executions, and record the ``prof.*{op=name}`` roofline
     gauge family (+ the ``prof.mem.*`` device-memory gauges).
@@ -236,26 +238,18 @@ def profile_op(name: str, fn, *args, iters: int = 1,
     Returns ``(out, stats)`` — ``stats`` holds flops / bytes / peak_bytes
     / roofline_util / achieved rates (whatever the backend exposes).
     ``work_elements`` (e.g. sequence length, query count) additionally
-    derives ``prof.melem_per_s``. With ``strict=False`` (the CLI default)
-    any failure degrades to ``(None, {"op": name, "error": ...})`` and a
-    ``prof.error`` counter instead of raising — profiling must never take
-    serving down.
+    derives ``prof.melem_per_s``. A failed compile or run raises: an op
+    that cannot be profiled cannot be served either.
     """
-    try:
-        sw = Stopwatch()
-        compiled = _aot(fn, *args)
-        compile_s = sw.lap()
-        out, steady_s, _ = time_compiled(compiled, *args, iters=iters)
-        stats = _record_cost_gauges(name, compiled, steady_s,
-                                    work_elements=work_elements)
-        stats["compile_s"] = compile_s
-        record_memory_gauges()
-        return out, stats
-    except Exception as e:                                    # noqa: BLE001
-        if strict:
-            raise
-        counter("prof.error", op=name).inc()
-        return None, {"op": name, "error": f"{type(e).__name__}: {e}"}
+    sw = Stopwatch()
+    compiled = _aot(fn, *args)
+    compile_s = sw.lap()
+    out, steady_s, _ = time_compiled(compiled, *args, iters=iters)
+    stats = _record_cost_gauges(name, compiled, steady_s,
+                                work_elements=work_elements)
+    stats["compile_s"] = compile_s
+    record_memory_gauges()
+    return out, stats
 
 
 def profiled_op(layer: str, op: str, fn, *args, batch: int = 1,
@@ -265,19 +259,14 @@ def profiled_op(layer: str, op: str, fn, *args, batch: int = 1,
     Emits the standard ``serve.<layer>.<op>.*`` metric family (latency
     histogram, compile_s/batch/qps gauges, calls counter, shape tracking)
     AND the ``prof.*{op=<layer>.<op>}`` cost-model gauges, compiling only
-    once. Falls back to plain ``timed_op`` (no prof gauges) when the
-    function cannot be AOT-lowered. Returns ``(out, steady_s,
-    compile_s)`` — drop-in for ``timed_op``.
+    once. Returns ``(out, steady_s, compile_s)`` — drop-in for
+    ``timed_op``.
     """
     name = f"{layer}.{op}"
     prefix = f"serve.{name}"
-    try:
-        sw = Stopwatch()
-        compiled = _aot(fn, *args)
-        compile_s = sw.lap()
-    except Exception:                                         # noqa: BLE001
-        counter("prof.error", op=name).inc()
-        return timed_op(layer, op, fn, *args, batch=batch, iters=iters)
+    sw = Stopwatch()
+    compiled = _aot(fn, *args)
+    compile_s = sw.lap()
     out, steady_s, _ = time_compiled(compiled, *args, iters=iters)
     track_shapes(name, *args)
     counter(prefix + ".calls").inc(1 + max(1, iters))

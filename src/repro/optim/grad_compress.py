@@ -95,9 +95,7 @@ def zero_residuals(params: Any) -> Any:
 def compressed_allreduce_mean(tree: Any, axis_name: str, bits: int) -> Any:
     """Mean-reduce a pytree across ``axis_name`` with compressed wire format
     (use under ``shard_map``). Each member ships packed planes + scale."""
-    # jax.lax.axis_size is jax ≥ 0.6; psum of 1 is the portable spelling
-    size = (jax.lax.axis_size(axis_name) if hasattr(jax.lax, "axis_size")
-            else jax.lax.psum(1, axis_name))
+    size = jax.lax.axis_size(axis_name)
 
     def one(g):
         words, scale = quantize_bitplanes(g, bits)

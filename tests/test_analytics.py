@@ -41,6 +41,20 @@ def _ranges(n: int, num: int, rng):
     return lo, hi
 
 
+# The ops under test, each one jitted call per query (the structure is an
+# argument, so every text of a given shape reuses one executable).
+_quantile = jax.jit(lambda w, a, b, k: range_quantile(w, a, b, k))
+_count = jax.jit(lambda w, a, b, s0, s1: range_count(w, a, b, s0, s1))
+_distinct = jax.jit(lambda w, a, b: range_distinct(w, a, b))
+_topk = jax.jit(lambda w, a, b, k: range_topk(w, a, b, k),
+                static_argnums=3)
+_eng_quantile = jax.jit(lambda e, a, b, k: e.range_quantile(a, b, k))
+_eng_count = jax.jit(lambda e, a, b, s0, s1: e.range_count(a, b, s0, s1))
+_eng_distinct = jax.jit(lambda e, a, b: e.range_distinct(a, b))
+_eng_topk = jax.jit(lambda e, a, b, k: e.range_topk(a, b, k),
+                    static_argnums=3)
+
+
 def _check_all_ops(seq, wm_ops, sigma, rng, tag, topk_k=6):
     """``wm_ops``: dict of callables mirroring the op signatures."""
     n = len(seq)
@@ -86,10 +100,10 @@ def test_single_matrix_ops_match_numpy(sigma):
         wm = build_wavelet_matrix(jnp.asarray(seq), sigma, sample_rate=128)
         rng = np.random.default_rng(sigma + 1)
         ops = {
-            "quantile": lambda a, b, k: range_quantile(wm, a, b, k),
-            "count": lambda a, b, s0, s1: range_count(wm, a, b, s0, s1),
-            "distinct": lambda a, b: range_distinct(wm, a, b),
-            "topk": lambda a, b, k: range_topk(wm, a, b, k),
+            "quantile": lambda a, b, k: _quantile(wm, a, b, k),
+            "count": lambda a, b, s0, s1: _count(wm, a, b, s0, s1),
+            "distinct": lambda a, b: _distinct(wm, a, b),
+            "topk": lambda a, b, k: _topk(wm, a, b, k),
         }
         _check_all_ops(seq, ops, sigma, rng, f"single/{name}/σ{sigma}")
 
@@ -174,10 +188,10 @@ def test_sharded_ops_match_numpy(sigma):
         assert eng.num_shards == 5
         rng = np.random.default_rng(sigma + 2)
         ops = {
-            "quantile": lambda a, b, k: eng.range_quantile(a, b, k),
-            "count": lambda a, b, s0, s1: eng.range_count(a, b, s0, s1),
-            "distinct": lambda a, b: eng.range_distinct(a, b),
-            "topk": lambda a, b, k: eng.range_topk(a, b, k),
+            "quantile": lambda a, b, k: _eng_quantile(eng, a, b, k),
+            "count": lambda a, b, s0, s1: _eng_count(eng, a, b, s0, s1),
+            "distinct": lambda a, b: _eng_distinct(eng, a, b),
+            "topk": lambda a, b, k: _eng_topk(eng, a, b, k),
         }
         _check_all_ops(seq, ops, sigma, rng, f"sharded/{name}/σ{sigma}")
 

@@ -85,17 +85,11 @@ os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=4"
 import jax, jax.numpy as jnp, numpy as np
 from jax.sharding import PartitionSpec as P
 from repro.optim.grad_compress import compressed_allreduce_mean
-if hasattr(jax.sharding, "AxisType"):
-    mesh = jax.make_mesh((4,), ("pod",),
-                         axis_types=(jax.sharding.AxisType.Auto,))
-else:
-    mesh = jax.make_mesh((4,), ("pod",))
-shard_map = getattr(jax, "shard_map", None)
-if shard_map is None:
-    from jax.experimental.shard_map import shard_map
+mesh = jax.make_mesh((4,), ("pod",),
+                     axis_types=(jax.sharding.AxisType.Auto,))
 x = jnp.asarray(np.random.default_rng(0).normal(size=(4, 64))
                 .astype(np.float32))
-f = shard_map(lambda g: compressed_allreduce_mean(g, "pod", bits=8),
+f = jax.shard_map(lambda g: compressed_allreduce_mean(g, "pod", bits=8),
               mesh=mesh, in_specs=P("pod", None),
               out_specs=P("pod", None))
 out = np.asarray(f(x))

@@ -109,7 +109,7 @@ def test_host_mesh_train_step_with_production_shardings():
     """End-to-end jit with NamedShardings from the production rules on a
     (1,1) host mesh — same code path as the 256-chip launch."""
     from jax.sharding import NamedSharding, PartitionSpec as P
-    from repro.launch.mesh import dp_axes, make_host_mesh, set_mesh
+    from repro.launch.mesh import dp_axes, make_host_mesh
     from repro.models import shard_ctx
     from repro.models.model import build_model, param_specs
     from repro.train import init_train_state, make_train_step
@@ -120,7 +120,7 @@ def test_host_mesh_train_step_with_production_shardings():
     sizes = dict(zip(mesh.axis_names, mesh.devices.shape))
     shard_ctx.set_mesh_context(dp_axes(mesh), sizes)
     try:
-        with set_mesh(mesh):
+        with jax.set_mesh(mesh):
             specs = param_specs(cfg, sizes)
             state = init_train_state(model, 0)
             pshard = jax.tree.map(lambda s: NamedSharding(mesh, s), specs,

@@ -6,10 +6,11 @@
   working set) — asserted end-to-end for the acceptance paths: the
   analytics quantile op, the construction path, and the Pallas kernel
   descent, with the gauges surviving a snapshot.json round trip.
-* the hardware model honors env overrides; utilization is bound_time /
-  measured_time so it must land in (0, 1] on a sane run.
-* non-strict profiling degrades to an error record + counter instead of
-  raising (profiling must never take serving down).
+* the hardware model is keyed by ``device_kind`` (the v5e entry carries
+  its published peaks), raises for a kind it does not list, and honors
+  env overrides; utilization is bound_time / measured_time so it must
+  land in (0, 1] on a sane run.
+* a failing op raises out of ``profile_op`` (no silent error record).
 * ``analyze_hlo`` stays importable from its old ``launch.hlo_analysis``
   home (back-compat shim) and agrees with the moved implementation.
 """
@@ -19,7 +20,7 @@ import jax.numpy as jnp
 import pytest
 
 from repro import obs
-from repro.obs.prof import (HW_MODELS, compiled_cost, compiled_memory,
+from repro.obs.prof import (PEAKS, compiled_cost, compiled_memory,
                             hw_model, live_memory_stats, profile_op,
                             profiled_op, record_memory_gauges)
 
@@ -34,11 +35,24 @@ def _clean_registry():
 
 def test_hw_model_env_override(monkeypatch):
     peak, bw = hw_model("cpu")
-    assert (peak, bw) == HW_MODELS["cpu"]
+    assert (peak, bw) == PEAKS["cpu"]
+    assert hw_model() == PEAKS[jax.devices()[0].device_kind]
     monkeypatch.setenv("REPRO_PEAK_FLOPS", "1e12")
     monkeypatch.setenv("REPRO_HBM_BW", "2e11")
     assert hw_model("cpu") == (1e12, 2e11)
-    assert hw_model("tpu") == (1e12, 2e11)      # override wins everywhere
+    assert hw_model("TPU v5 lite") == (1e12, 2e11)   # override wins everywhere
+
+
+def test_hw_model_v5e_published_peaks():
+    # Google Cloud documentation, "TPU v5e": 197 TFLOP/s bf16, 819 GB/s
+    assert hw_model("TPU v5 lite") == (197e12, 819e9)
+
+
+@pytest.mark.parametrize("kind", ["TPU v4", "TPU v6 lite", "tpu", "gpu",
+                                  "NVIDIA A100-SXM4-40GB"])
+def test_hw_model_unknown_kind_raises(kind):
+    with pytest.raises(KeyError, match="no published peaks"):
+        hw_model(kind)
 
 
 def test_compiled_cost_and_memory_of_matmul():
@@ -77,12 +91,12 @@ def test_profile_op_records_roofline_family():
 def test_profile_op_nonstrict_degrades():
     def boom(x):
         raise RuntimeError("nope")
-    out, stats = profile_op("t.bad", boom, jnp.ones(4))
-    assert out is None and "error" in stats
+    with pytest.raises(RuntimeError, match="nope"):
+        profile_op("t.bad", boom, jnp.ones(4))
+    with pytest.raises(RuntimeError, match="nope"):
+        profiled_op("t", "bad", boom, jnp.ones(4))
     snap = obs.REGISTRY.snapshot()
-    assert snap["counters"]["prof.error{op=t.bad}"] == 1
-    with pytest.raises(RuntimeError):
-        profile_op("t.bad", boom, jnp.ones(4), strict=True)
+    assert not any(k.startswith("prof.") for k in snap["gauges"])
 
 
 def test_profiled_op_emits_both_families():
