@@ -22,6 +22,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro import obs
 from repro.analytics.engine import (sharded_range_count,
                                     sharded_range_distinct,
                                     sharded_range_histogram,
@@ -31,7 +32,7 @@ from repro.core.wavelet_matrix import (WaveletMatrix, build_wavelet_matrix,
                                        num_levels, wm_access, wm_rank,
                                        wm_select)
 
-from .shard_build import build_shards_stacked
+from .shard_build import build_mode, build_shards_stacked
 
 _I32 = jnp.int32
 
@@ -161,33 +162,44 @@ def build_compressed_corpus(tokens: np.ndarray, sigma: int,
     Padding tokens (id 0) exist only in the slack tail of the last shard
     and are never addressed (n records the true length; the shard
     histograms subtract them).
+
+    Host spans: ``store.build`` (attributes ``shards``, ``sigma``,
+    ``mode``) over the shard builds (``data.shard_build``'s spans) and
+    ``store.build.histogram`` (the per-shard ``bincount``, the cumsum and
+    the upload of ``shard_counts``); none waits for the device.
     """
     n = int(len(tokens))
     shard_size = 1 << shard_bits
     num_shards = max(1, (n + shard_size - 1) // shard_size)
-    pad = num_shards * shard_size - n
-    toks = np.asarray(tokens, np.uint32)
-    if pad:
-        toks = np.concatenate([toks, np.zeros(pad, np.uint32)])
-    shards_np = toks.reshape(num_shards, shard_size)
+    with obs.span("store.build", shards=num_shards, sigma=sigma,
+                  mode=build_mode(num_shards, parallel)):
+        pad = num_shards * shard_size - n
+        toks = np.asarray(tokens, np.uint32)
+        if pad:
+            toks = np.concatenate([toks, np.zeros(pad, np.uint32)])
+        shards_np = toks.reshape(num_shards, shard_size)
 
-    # jit_loop compiles the whole builder once on the sequential path so
-    # every shard reuses one executable.
-    stacked = build_shards_stacked(
-        lambda s: build_wavelet_matrix(s, sigma, tau=tau, big_step=big_step,
-                                       sample_rate=sample_rate),
-        shards_np, parallel=parallel, jit_loop=True)
+        def wm_shard_build(s):
+            return build_wavelet_matrix(s, sigma, tau=tau, big_step=big_step,
+                                        sample_rate=sample_rate)
 
-    hist = np.zeros((num_shards, sigma), np.int64)
-    for i, s in enumerate(shards_np):
-        hist[i] = np.bincount(s, minlength=sigma)[:sigma]
-    if pad:  # padding tokens are id 0: remove them from the last histogram
-        hist[-1, 0] -= pad
-    cum = np.concatenate([np.zeros((1, sigma), np.int64),
-                          np.cumsum(hist, axis=0)]).astype(np.int32)
+        # jit_loop compiles the whole builder once on the sequential path
+        # so every shard reuses one executable; the named function names
+        # the program (jit_wm_shard_build, pmap_wm_shard_build).
+        stacked = build_shards_stacked(wm_shard_build, shards_np,
+                                       parallel=parallel, jit_loop=True)
 
-    return CompressedCorpus(shards=stacked,
-                            shard_counts=jnp.asarray(cum),
+        with obs.span("store.build.histogram"):
+            hist = np.zeros((num_shards, sigma), np.int64)
+            for i, s in enumerate(shards_np):
+                hist[i] = np.bincount(s, minlength=sigma)[:sigma]
+            if pad:  # padding tokens are id 0: remove them from the last
+                hist[-1, 0] -= pad
+            cum = np.concatenate([np.zeros((1, sigma), np.int64),
+                                  np.cumsum(hist, axis=0)]).astype(np.int32)
+            shard_counts = jnp.asarray(cum)
+
+    return CompressedCorpus(shards=stacked, shard_counts=shard_counts,
                             n=n, sigma=sigma, shard_bits=shard_bits)
 
 

@@ -17,6 +17,12 @@ historical host-side Python loop into a traced build:
 Any traced path requires ``build_one`` to be trace-safe (no host syncs on
 data values) — the wavelet-matrix and FM-index builders both are when
 their alphabet size is pinned.
+
+Host spans: ``store.build.shards`` (the upload and the per-shard dispatch
+loop, or the vmap or pmap call; JAX's trace, lower and compile phases
+land under it) and ``store.build.stack`` (stacking the loop's per-shard
+trees, reshaping the pmap's device axis away; nothing on the vmap path).
+Neither waits for the device.
 """
 from __future__ import annotations
 
@@ -24,6 +30,26 @@ from typing import Callable
 
 import jax
 import jax.numpy as jnp
+
+from repro import obs
+
+
+def build_mode(num_shards: int, parallel: str | bool = "auto") -> str:
+    """"loop", "vmap" or "pmap": how ``build_shards_stacked`` builds
+    ``num_shards`` shards on this process's devices (module doc)."""
+    ndev = jax.local_device_count()
+    if parallel == "auto":
+        mode = "pmap" if (ndev > 1 and num_shards > 1) else "loop"
+    elif parallel is True:
+        mode = "pmap" if (ndev > 1 and num_shards > 1) else "vmap"
+    elif parallel is False:
+        mode = "loop"
+    else:
+        raise ValueError(f"parallel must be 'auto'/True/False, "
+                         f"got {parallel!r}")
+    if mode == "pmap" and num_shards % ndev != 0:
+        mode = "vmap"                  # ragged over devices → one program
+    return "loop" if num_shards == 1 else mode
 
 
 def build_shards_stacked(build_one: Callable, shards: jax.Array, *,
@@ -55,30 +81,23 @@ def build_shards_stacked(build_one: Callable, shards: jax.Array, *,
                                          parallel=parallel,
                                          jit_loop=jit_loop, retries=0),
             retries=retries, backoff_s=backoff_s)
-    shards = jnp.asarray(shards)
-    num_shards = shards.shape[0]
-    ndev = jax.local_device_count()
-
-    if parallel == "auto":
-        mode = "pmap" if (ndev > 1 and num_shards > 1) else "loop"
-    elif parallel is True:
-        mode = "pmap" if (ndev > 1 and num_shards > 1) else "vmap"
-    elif parallel is False:
-        mode = "loop"
-    else:
-        raise ValueError(f"parallel must be 'auto'/True/False, "
-                         f"got {parallel!r}")
-    if mode == "pmap" and num_shards % ndev != 0:
-        mode = "vmap"                  # ragged over devices → one program
-
-    if mode == "loop" or num_shards == 1:
-        fn = jax.jit(build_one) if jit_loop else build_one
-        built = [fn(shards[s]) for s in range(num_shards)]
-        return jax.tree.map(lambda *xs: jnp.stack(xs), *built)
-    if mode == "vmap":
-        return jax.vmap(build_one)(shards)
-    per = num_shards // ndev
-    out = jax.pmap(jax.vmap(build_one))(
-        shards.reshape(ndev, per, shards.shape[1]))
-    return jax.tree.map(
-        lambda l: l.reshape((num_shards,) + l.shape[2:]), out)
+    num_shards = len(shards)
+    mode = build_mode(num_shards, parallel)
+    with obs.span("store.build.shards"):
+        shards = jnp.asarray(shards)
+        if mode == "loop":
+            fn = jax.jit(build_one) if jit_loop else build_one
+            built = [fn(shards[s]) for s in range(num_shards)]
+        elif mode == "vmap":
+            built = jax.vmap(build_one)(shards)
+        else:
+            ndev = jax.local_device_count()
+            built = jax.pmap(jax.vmap(build_one))(
+                shards.reshape(ndev, num_shards // ndev, shards.shape[1]))
+    with obs.span("store.build.stack"):
+        if mode == "loop":
+            return jax.tree.map(lambda *xs: jnp.stack(xs), *built)
+        if mode == "vmap":
+            return built
+        return jax.tree.map(
+            lambda l: l.reshape((num_shards,) + l.shape[2:]), built)

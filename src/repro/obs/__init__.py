@@ -9,7 +9,8 @@ instrument themselves without cycles.
 * :mod:`repro.obs.metrics` — process-global registry of counters, gauges
   and streaming log-bucket histograms; true no-ops when disabled.
 * :mod:`repro.obs.spans`   — nested ``span()`` context manager forwarding
-  to ``jax.profiler.TraceAnnotation``/``named_scope``.
+  to ``jax.profiler.TraceAnnotation``; finished spans kept in memory
+  (``finished_spans()``), JAX's trace/lower/compile phases as child spans.
 * :mod:`repro.obs.timing`  — ``time_compiled``/``timed_op`` (the one timer
   the CLIs and benchmarks share; compile_s separated from steady-state)
   and ``track_shapes`` jit-recompile tracking.
@@ -45,7 +46,8 @@ from .metrics import (REGISTRY, Counter, Gauge, Histogram, MetricsRegistry,
 from .prof import (analyze_hlo, hw_model, live_memory_stats, profile_op,
                    profiled_op, record_memory_gauges, start_trace,
                    stop_trace, trace)
-from .spans import current_span, event, span
+from .spans import (clear_spans, current_span, event,
+                    finished_spans, span)
 from .timing import (Stopwatch, reset_shape_tracking, time_compiled,
                      timed_op, track_shapes)
 
@@ -53,7 +55,7 @@ __all__ = [
     "REGISTRY", "Counter", "Gauge", "Histogram", "MetricsRegistry",
     "counter", "gauge", "histogram", "parse_key",
     "enable", "disable", "disabled", "enabled",
-    "span", "current_span", "event",
+    "span", "current_span", "event", "finished_spans", "clear_spans",
     "Stopwatch", "time_compiled", "timed_op", "track_shapes",
     "reset_shape_tracking",
     "configure", "metrics_dir", "emit_event", "write_snapshot",

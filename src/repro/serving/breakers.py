@@ -60,6 +60,8 @@ class ShardBreakers:
         self._probe = probe
         self._pool = concurrent.futures.ThreadPoolExecutor(
             max_workers=2, thread_name_prefix="breaker-probe")
+        #: probes run since construction
+        self.probes_run = 0
         self.resize(num_shards)
 
     def resize(self, num_shards: int) -> None:
@@ -75,6 +77,8 @@ class ShardBreakers:
     # ---- hedged probe ---------------------------------------------------
     def _hedged_probe(self, s: int) -> bool:
         cfg = self.config
+        self.probes_run += 1
+        obs.counter("serve.frontend.probes").inc()
         t0 = self.clock.now()
         fut = self._pool.submit(self._probe, s)
         try:
@@ -93,7 +97,19 @@ class ShardBreakers:
 
     # ---- state machine --------------------------------------------------
     def refresh(self) -> np.ndarray:
-        """Run due probes, advance breaker states, return the mask."""
+        """Run due probes, advance breaker states, return the mask.
+
+        Host span ``frontend.breakers.refresh``, its attribute ``probes``
+        the probes this round ran (each counts as
+        ``serve.frontend.probes``).
+        """
+        with obs.span("frontend.breakers.refresh") as sp:
+            before = self.probes_run
+            mask = self._advance()
+            sp.set("probes", self.probes_run - before)
+        return mask
+
+    def _advance(self) -> np.ndarray:
         cfg = self.config
         for s in range(self.num_shards):
             now = self.clock.now()

@@ -5,11 +5,15 @@
 * counter/gauge thread-safety under concurrent writers and under
   vmapped shard builds (trace-time increments must not corrupt state)
 * disabled mode is a true no-op (no state mutation, no export)
-* span nesting, attribute propagation, and event correlation
+* span nesting, attribute propagation, and event correlation; finished
+  spans kept in memory; JAX's trace/lower/compile phases as child spans
+  (persistent-cache hits marked); the construction and breaker spans, and
+  a shard builder lowered the same with a span open as with obs off
 * exporter round trip: snapshot + JSONL events, Prometheus text
 * timed_op emits the full ``serve.*`` metric family; track_shapes counts
   distinct signatures once
 """
+import contextlib
 import json
 import threading
 
@@ -186,6 +190,169 @@ def test_span_sync_blocks_on_device_value():
         out = sp.sync(jnp.arange(8) * 2)
     assert sp.dur_s is not None
     assert int(np.asarray(out)[-1]) == 14
+
+
+def test_finished_spans_are_kept_in_memory():
+    obs.clear_spans()
+    with obs.span("outer", a=1) as so:
+        with obs.span("inner") as si:
+            pass
+    got = obs.finished_spans()
+    assert [sp.name for sp in got] == ["inner", "outer"]
+    assert got[0] is si and got[1] is so
+    assert so.t0 <= si.t0 <= si.t1 <= so.t1
+    assert si.parent_id == so.span_id and so.attrs == {"a": 1}
+    assert so.dur_s == pytest.approx(so.t1 - so.t0)
+    assert so.ts > 1e9                   # wall clock, Unix seconds
+    obs.clear_spans()
+    assert obs.finished_spans() == []
+
+
+def _fresh_jit(k):
+    """A function JAX has not traced yet: jitting it traces, lowers and
+    compiles anew (the same program for every call)."""
+    def fresh(x):
+        return jnp.sin(x) * k + jnp.cumsum(x)
+    return jax.jit(fresh)
+
+
+def _children(parent):
+    return {sp.name: sp for sp in obs.finished_spans()
+            if sp.parent_id == parent.span_id}
+
+
+def test_jax_compile_phases_are_child_spans():
+    obs.clear_spans()
+    with obs.span("compiling") as parent:
+        _fresh_jit(3.0)(jnp.arange(16.0)).block_until_ready()
+    kids = _children(parent)
+    assert {"jax.trace", "jax.lower", "jax.compile"} <= set(kids)
+    for name in ("jax.trace", "jax.lower", "jax.compile"):
+        sp = kids[name]
+        assert parent.t0 <= sp.t0 <= sp.t1 <= parent.t1
+        assert sp.path == f"compiling/{name}"
+        assert "fresh" in sp.attrs["fun"]
+    assert kids["jax.trace"].t1 <= kids["jax.lower"].t0 + 1e-3
+    assert kids["jax.compile"].attrs["cache_hit"] is False
+    snap = obs.REGISTRY.snapshot()["counters"]
+    assert snap.get("jax.compiles{cache=miss}", 0) >= 1
+
+
+@pytest.fixture
+def persistent_cache(tmp_path):
+    from jax.experimental.compilation_cache import compilation_cache
+    keys = ("jax_enable_compilation_cache", "jax_compilation_cache_dir",
+            "jax_persistent_cache_min_compile_time_secs",
+            "jax_persistent_cache_min_entry_size_bytes")
+    was = {k: getattr(jax.config, k) for k in keys}
+    jax.config.update("jax_enable_compilation_cache", True)
+    jax.config.update("jax_compilation_cache_dir", str(tmp_path))
+    jax.config.update("jax_persistent_cache_min_compile_time_secs", 0)
+    jax.config.update("jax_persistent_cache_min_entry_size_bytes", 0)
+    compilation_cache.reset_cache()
+    yield tmp_path
+    for k, v in was.items():
+        jax.config.update(k, v)
+    compilation_cache.reset_cache()
+
+
+def test_persistent_cache_hit_marks_the_compile_span(persistent_cache):
+    x = jnp.arange(16.0)
+    hits = []
+    for _ in range(2):                  # the second copy loads the first
+        obs.clear_spans()
+        with obs.span("compiling") as parent:
+            _fresh_jit(5.0)(x).block_until_ready()
+        hits.append(_children(parent)["jax.compile"].attrs["cache_hit"])
+    assert hits == [False, True]
+    snap = obs.REGISTRY.snapshot()["counters"]
+    assert snap.get("jax.compiles{cache=hit}") == 1
+
+
+def test_disabled_mode_records_no_spans_and_no_phases():
+    obs.clear_spans()
+    with obs.disabled():
+        with obs.span("compiling"):
+            _fresh_jit(7.0)(jnp.arange(16.0)).block_until_ready()
+    assert obs.finished_spans() == []
+    assert not any(k.startswith("jax.compiles")
+                   for k in obs.REGISTRY.snapshot()["counters"])
+
+
+def test_compile_work_outside_any_span_is_not_recorded():
+    obs.clear_spans()
+    with obs.span("first"):             # registers the JAX listener
+        pass
+    obs.clear_spans()
+    _fresh_jit(9.0)(jnp.arange(16.0)).block_until_ready()
+    assert obs.finished_spans() == []
+
+
+@pytest.mark.parametrize("parallel,mode", [(False, "loop"), (True, "vmap")])
+def test_store_build_spans(parallel, mode):
+    from repro.data.compressed_store import build_compressed_corpus
+    toks = np.random.default_rng(5).integers(0, 50, 3000).astype(np.uint32)
+    for _ in range(2):                  # the second build is the warm one
+        obs.clear_spans()
+        corpus = build_compressed_corpus(toks, 50, shard_bits=10,
+                                         sample_rate=128, parallel=parallel)
+        jax.block_until_ready(corpus)
+    spans = obs.finished_spans()
+    (build,) = [sp for sp in spans if sp.name == "store.build"]
+    assert build.attrs == {"shards": 3, "sigma": 50, "mode": mode}
+    kids = [sp for sp in spans if sp.parent_id == build.span_id]
+    assert [sp.name for sp in kids] == ["store.build.shards",
+                                        "store.build.stack",
+                                        "store.build.histogram"]
+    for sp in kids:
+        assert build.t0 <= sp.t0 <= sp.t1 <= build.t1
+    phases = {sp.name for sp in spans if sp.name.startswith("jax.")}
+    assert all(sp.path.startswith("store.build/") for sp in spans
+               if sp.name.startswith("jax."))
+    if mode == "loop":                  # a fresh builder: traced anew
+        assert {"jax.trace", "jax.lower", "jax.compile"} <= phases
+        assert len(spans) <= 8          # a bounded cost per build
+
+
+def test_spans_leave_the_shard_builder_program_unchanged(monkeypatch):
+    """The lowered shard builder, debug locations included, is the same
+    with a ``store.build`` span open as with obs disabled."""
+    from repro.data import compressed_store
+    real = compressed_store.build_shards_stacked
+    texts = []
+
+    def lowering(build_one, shards, **kw):
+        assert obs.current_span() is None or \
+            obs.current_span().name == "store.build"
+        texts.append(jax.jit(build_one).lower(
+            jnp.asarray(shards[0])).as_text(debug_info=True))
+        return real(build_one, shards, **kw)
+
+    monkeypatch.setattr(compressed_store, "build_shards_stacked", lowering)
+    toks = np.random.default_rng(6).integers(0, 40, 2048).astype(np.uint32)
+    for ctx in (obs.disabled, contextlib.nullcontext):
+        with ctx():
+            compressed_store.build_compressed_corpus(toks, 40, shard_bits=10,
+                                                     sample_rate=128)
+    assert len(texts) == 2
+    assert "jit_wm_shard_build" in texts[0]
+    assert texts[0] == texts[1]
+
+
+def test_breakers_refresh_span_counts_probes():
+    from repro.robust.clock import FakeClock
+    from repro.serving.breakers import ShardBreakers
+    clock = FakeClock()
+    br = ShardBreakers(4, lambda s: True, clock=clock)
+    obs.clear_spans()
+    br.refresh()                        # every shard is due
+    br.refresh()                        # none is, yet
+    clock.advance(1.0)
+    br.refresh()
+    got = [sp for sp in obs.finished_spans()
+           if sp.name == "frontend.breakers.refresh"]
+    assert [sp.attrs["probes"] for sp in got] == [4, 0, 4]
+    assert obs.counter("serve.frontend.probes").value == 8
 
 
 # -------------------------------------------------------------------------
