@@ -20,7 +20,8 @@ from .sort import (bucket_ranks, counting_rank, radix_sort_stable,
                    sort_pass, sort_permutation)
 from .wavelet_matrix import (WaveletMatrix, build_wavelet_matrix,
                              build_wavelet_matrix_levelwise, num_levels,
-                             reverse_bits, wm_access, wm_child_interval,
+                             reverse_bits, wm_access, wm_access_descend,
+                             wm_block_starts, wm_child_interval, wm_descend,
                              wm_interval_zeros, wm_position_step, wm_rank,
                              wm_select)
 from .wavelet_tree import (WaveletTree, build_wavelet_tree,
@@ -38,7 +39,8 @@ __all__ = [
     "bucket_ranks", "counting_rank", "radix_sort_stable", "sort_pass",
     "sort_permutation",
     "WaveletMatrix", "build_wavelet_matrix", "build_wavelet_matrix_levelwise",
-    "num_levels", "reverse_bits", "wm_access", "wm_child_interval",
+    "num_levels", "reverse_bits", "wm_access", "wm_access_descend",
+    "wm_block_starts", "wm_child_interval", "wm_descend",
     "wm_interval_zeros", "wm_position_step", "wm_rank", "wm_select",
     "WaveletTree", "build_wavelet_tree", "build_wavelet_tree_dd",
     "build_wavelet_tree_levelwise", "wt_access", "wt_rank", "wt_select",

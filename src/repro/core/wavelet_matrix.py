@@ -40,6 +40,7 @@ from typing import List
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 from . import bitops
 from .rank_select import (BitVector, access_bit, build_bitvector,
@@ -293,15 +294,59 @@ def wm_position_step(wm: WaveletMatrix, l: int, p: jax.Array):
 # Queries
 # --------------------------------------------------------------------------
 
-def wm_access(wm: WaveletMatrix, i: jax.Array) -> jax.Array:
-    """Symbol at position i. Vectorized over i; O(logσ) rank calls."""
+def wm_access_descend(wm: WaveletMatrix, i: jax.Array):
+    """(symbol c at position i, position where i's descent ends).
+
+    The end position is ``wm_descend(wm, c, i)``, so an LF step reads it
+    here instead of ranking c again. Vectorized over i."""
     i = jnp.asarray(i, jnp.int32)
     c = jnp.zeros_like(i)
     p = i
     for l in range(wm.nbits):
         bit, p = wm_position_step(wm, l, p)
         c = (c << 1) | bit
-    return c
+    return c, p
+
+
+def wm_access(wm: WaveletMatrix, i: jax.Array) -> jax.Array:
+    """Symbol at position i. Vectorized over i; O(logσ) rank calls."""
+    return wm_access_descend(wm, i)[0]
+
+
+def wm_descend(wm: WaveletMatrix, c: jax.Array, i: jax.Array) -> jax.Array:
+    """Follow position i down every level along c's bits: one ``rank1``
+    per level. The end is ``wm_block_starts(...)[c] + wm_rank(wm, c, i)``:
+    the last level holds c's occurrences before i right after the start
+    of c's block. Vectorized."""
+    c = jnp.asarray(c, jnp.int32)
+    p = jnp.asarray(i, jnp.int32)
+    for l in range(wm.nbits):
+        bit = (c >> (wm.nbits - 1 - l)) & 1
+        ones = rank1(wm.level(l).rank, p)
+        p = jnp.where(bit == 0, p - ones, wm.zeros[l] + ones)
+    return p
+
+
+def wm_block_starts(counts: jax.Array, nbits: int) -> jax.Array:
+    """Row where each code's block starts after the last level, from the
+    symbol counts alone: ``wm_descend(wm, c, 0)`` for every code c.
+
+    The last level holds the symbols stably sorted by their bit-reversed
+    code, so the start of c's block is the count of symbols whose
+    reversed code is smaller. ``counts`` is indexed by code (0 for codes
+    that never occur); a code is read through its low ``nbits`` bits, as
+    the descent reads it. The order is static, made once on the host.
+    """
+    code = np.arange(counts.shape[0]) & ((1 << nbits) - 1)
+    rev = np.zeros_like(code)
+    for b in range(nbits):
+        rev |= ((code >> b) & 1) << (nbits - 1 - b)
+    order = np.argsort(rev, kind="stable")
+    # codes that read as the same nbits share a start: count what is
+    # strictly smaller
+    first = np.searchsorted(rev[order], rev, side="left")
+    ordered = jnp.asarray(counts, jnp.int32)[order]
+    return (jnp.cumsum(ordered) - ordered)[first]
 
 
 def wm_rank(wm: WaveletMatrix, c: jax.Array, i: jax.Array) -> jax.Array:

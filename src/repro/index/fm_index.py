@@ -1,11 +1,13 @@
 """FM-index: backward search over a wavelet-matrix BWT (count + locate).
 
 This is *the* workload the paper's structures exist for: every step of
-backward search is two `rank` queries on the BWT's wavelet matrix, so a
-batch of B patterns of length L issues 2·B·L rank calls — all independent,
-all vmapped. The index is a frozen-dataclass pytree (arrays are leaves,
-sizes static), so it crosses ``jax.jit`` boundaries and vmaps like any
-other operand.
+backward search is two LF mappings on the BWT's wavelet matrix, so a
+batch of B patterns of length L issues 2·B·L of them — all independent,
+all vmapped. An LF mapping is one descent of its position down the
+levels (one ``rank1`` each) plus a gather from a per-shard base table
+made from ``C``. The index is a frozen-dataclass pytree (arrays are
+leaves, sizes static), so it crosses ``jax.jit`` boundaries and vmaps
+like any other operand.
 
 Structure (Ferragina–Manzini, wavelet-matrix occ as in Claude & Navarro):
 
@@ -16,7 +18,7 @@ Structure (Ferragina–Manzini, wavelet-matrix occ as in Claude & Navarro):
                  rows j with sa[j] ≡ 0 (mod sample_rate) are marked in a
                  rank bitvector and their sa values stored compacted in row
                  order; a locate walks LF at most sample_rate−1 steps to a
-                 marked row (each step = 1 access + 1 rank on the wavelet
+                 marked row (each step = 1 access descent of the wavelet
                  matrix), then reads the sample. Space for samples is
                  O(m/sample_rate) words — the index stays succinct.
 
@@ -33,11 +35,13 @@ from dataclasses import dataclass, field
 import jax
 import jax.numpy as jnp
 
+from repro import obs
 from repro.core.rank_select import (BinaryRank, access_bit,
                                     build_binary_rank, rank1)
 from repro.core import bitops
 from repro.core.wavelet_matrix import (WaveletMatrix, build_wavelet_matrix,
-                                       wm_access, wm_rank)
+                                       wm_access_descend, wm_block_starts,
+                                       wm_descend)
 
 from .bwt import SENTINEL_SHIFT, bwt_encode
 
@@ -119,6 +123,17 @@ def build_fm_index(seq, sigma: int, *, sample_rate: int = 32,
 # backward search
 # ----------------------------------------------------------------------
 
+def _lf_base(fm: FMIndex) -> jax.Array:
+    """(σ+2,) table with LF(c, i) = base[c] + wm_descend(wm, c, i).
+
+    LF(c, i) = C[c] + rank(c, i), and the descent of i along c ends at
+    start[c] + rank(c, i), where start[c] is the first row of c's block at
+    the last level. So base = C − start, with start from C's differences
+    (the out-of-alphabet code σ+1 never occurs: count 0)."""
+    counts = jnp.diff(fm.C, append=fm.C[-1:])
+    return fm.C - wm_block_starts(counts, fm.wm.nbits)
+
+
 def _backward_range(fm: FMIndex, pattern: jax.Array,
                     length: jax.Array):
     """(lo, hi) of the SA range matching one padded pattern.
@@ -131,6 +146,10 @@ def _backward_range(fm: FMIndex, pattern: jax.Array,
     length = jnp.asarray(length, _I32)
     L = pattern.shape[0]
     m = jnp.asarray(fm.m, _I32)
+    # one descent per range end, nbits rank1 each
+    obs.counter("index.backward_step",
+                rank1_per_step=2 * fm.wm.nbits).inc()
+    lf_base = _lf_base(fm)
 
     def body(t, state):
         lo, hi = state
@@ -138,10 +157,10 @@ def _backward_range(fm: FMIndex, pattern: jax.Array,
         c = jnp.clip(pattern[i] + SENTINEL_SHIFT, 0, fm.sigma + 1)
         in_alpha = (pattern[i] >= 0) & (pattern[i] < fm.sigma)
         active = i < length
-        base = fm.C[c]
-        hi2 = base + wm_rank(fm.wm, c, hi)
+        base = lf_base[c]
+        hi2 = base + wm_descend(fm.wm, c, hi)
         # an out-of-alphabet symbol (e.g. shard padding) empties the range
-        lo2 = jnp.where(in_alpha, base + wm_rank(fm.wm, c, lo), hi2)
+        lo2 = jnp.where(in_alpha, base + wm_descend(fm.wm, c, lo), hi2)
         lo = jnp.where(active, lo2, lo)
         hi = jnp.where(active, hi2, hi)
         return lo, hi
@@ -173,20 +192,23 @@ def fm_count(fm: FMIndex, patterns: jax.Array,
 # locate (sampled-SA LF walk)
 # ----------------------------------------------------------------------
 
-def _lf_step(fm: FMIndex, j: jax.Array) -> jax.Array:
-    """LF(j): the row whose suffix starts one text position earlier."""
-    c = wm_access(fm.wm, j)
-    return fm.C[c] + wm_rank(fm.wm, c, j)
+def _lf_step(fm: FMIndex, lf_base: jax.Array, j: jax.Array) -> jax.Array:
+    """LF(j): the row whose suffix starts one text position earlier.
+    Reading BWT[j] descends j along its own symbol, so the descent's end
+    is the rank part of LF: one descent, no second rank."""
+    c, end = wm_access_descend(fm.wm, j)
+    return lf_base[c] + end
 
 
-def _locate_row(fm: FMIndex, j: jax.Array) -> jax.Array:
+def _locate_row(fm: FMIndex, lf_base: jax.Array,
+                j: jax.Array) -> jax.Array:
     """Text position of SA row j: walk LF to the nearest marked row."""
     j = jnp.asarray(j, _I32)
 
     def body(_, state):
         j, steps, done = state
         done2 = done | (access_bit(fm.mark, j) > 0)
-        j2 = jnp.where(done2, j, _lf_step(fm, j))
+        j2 = jnp.where(done2, j, _lf_step(fm, lf_base, j))
         steps2 = jnp.where(done2, steps, steps + 1)
         return j2, steps2, done2
 
@@ -208,7 +230,8 @@ def fm_locate(fm: FMIndex, pattern: jax.Array, length: jax.Array,
                              jnp.asarray(length, _I32))
     ks = jnp.arange(max_hits, dtype=_I32)
     rows = jnp.minimum(lo + ks, jnp.asarray(fm.m - 1, _I32))
-    pos = jax.vmap(lambda r: _locate_row(fm, r))(rows)
+    lf_base = _lf_base(fm)
+    pos = jax.vmap(lambda r: _locate_row(fm, lf_base, r))(rows)
     valid = ks < (hi - lo)
     out = jnp.sort(jnp.where(valid, pos, jnp.asarray(fm.m, _I32)))
     return jnp.where(out >= fm.m, jnp.asarray(-1, _I32), out)

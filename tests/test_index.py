@@ -8,10 +8,14 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from repro import obs
+from repro.core.wavelet_matrix import (wm_access_descend, wm_block_starts,
+                                       wm_descend, wm_rank)
 from repro.data import make_corpus
 from repro.index import (build_fm_index, build_sharded_index, bwt_decode,
                          bwt_encode, fm_count, fm_locate, suffix_array,
                          suffix_array_naive)
+from repro.index.fm_index import _lf_base
 
 
 def _naive_count(text: np.ndarray, pat: np.ndarray, plen: int) -> int:
@@ -113,22 +117,25 @@ def test_fm_count_batch64_under_jit_pytree():
 
 
 def test_fm_locate_exact_and_subset():
-    n, sigma = 400, 8
+    n = 400
     rng = np.random.default_rng(3)
-    text = rng.integers(0, sigma, n).astype(np.int64)
-    fm = build_fm_index(jnp.asarray(text, jnp.int32), sigma, sample_rate=8)
-    for plen in (1, 2, 4):
-        s = int(rng.integers(0, n - plen))
-        pat = text[s:s + plen].astype(np.int32)
-        ref = [i for i in range(n - plen + 1)
-               if np.array_equal(text[i:i + plen], pat)]
-        got = np.asarray(fm_locate(fm, jnp.asarray(pat), jnp.int32(plen),
-                                   max_hits=64))
-        hits = [int(x) for x in got if x >= 0]
-        if len(ref) <= 64:
-            assert hits == ref, plen          # all matches, text order
-        else:
-            assert len(hits) == 64 and set(hits) <= set(ref), plen
+    for sigma in (8, 4, 256):      # 3 and 9 levels of LF descent
+        text = rng.integers(0, sigma, n).astype(np.int64)
+        fm = build_fm_index(jnp.asarray(text, jnp.int32), sigma,
+                            sample_rate=8)
+        for plen in (1, 2, 4):
+            s = int(rng.integers(0, n - plen))
+            pat = text[s:s + plen].astype(np.int32)
+            ref = [i for i in range(n - plen + 1)
+                   if np.array_equal(text[i:i + plen], pat)]
+            got = np.asarray(fm_locate(fm, jnp.asarray(pat),
+                                       jnp.int32(plen), max_hits=64))
+            hits = [int(x) for x in got if x >= 0]
+            if len(ref) <= 64:
+                assert hits == ref, (sigma, plen)  # all matches, in order
+            else:
+                assert len(hits) == 64 and set(hits) <= set(ref), (
+                    sigma, plen)
 
 
 def test_fm_locate_adversarial_texts():
@@ -142,6 +149,76 @@ def test_fm_locate_adversarial_texts():
                                    max_hits=256))
         hits = [int(x) for x in got if x >= 0]
         assert hits == ref, name
+
+
+# ---------------------------------------------------------------------------
+# LF as one descent: base table from C
+# ---------------------------------------------------------------------------
+
+def _lf_texts(sigma: int):
+    rng = np.random.default_rng(sigma + 1)
+    return {"random": rng.integers(0, sigma, 300),
+            "one_symbol": np.full(64, sigma // 2),
+            "each_once": rng.permutation(sigma)}
+
+
+# σ = 7: the out-of-alphabet code σ+1 = 8 needs a 4th bit the 3 levels do
+# not have, so the descent reads it as code 0 (as wm_rank does)
+_LF_CASES = [(sigma, kind) for sigma in (4, 7, 256)
+             for kind in ("random", "one_symbol", "each_once")]
+
+
+@pytest.mark.parametrize("sigma,kind", _LF_CASES)
+def test_block_starts_from_C_equal_descent_from_zero(sigma, kind):
+    """Every index of C, absent codes and σ+1 included."""
+    text = _lf_texts(sigma)[kind]
+    fm = build_fm_index(jnp.asarray(text, jnp.int32), sigma, sample_rate=8)
+    k = fm.C.shape[0]
+    assert k == sigma + 2
+    counts = jnp.diff(fm.C, append=fm.C[-1:])
+    starts = np.asarray(wm_block_starts(counts, fm.wm.nbits))
+    codes = jnp.arange(k, dtype=jnp.int32)
+    from_zero = np.asarray(wm_descend(fm.wm, codes, jnp.zeros_like(codes)))
+    assert np.array_equal(starts, from_zero)
+
+
+@pytest.mark.parametrize("sigma,kind", _LF_CASES)
+def test_lf_base_plus_descent_equals_C_plus_rank(sigma, kind):
+    """base[c] + descend(c, i) = C[c] + rank(c, i) at random i in [0, m],
+    and an access descent ends where the descent along its symbol does."""
+    text = _lf_texts(sigma)[kind]
+    fm = build_fm_index(jnp.asarray(text, jnp.int32), sigma, sample_rate=8)
+    rng = np.random.default_rng(sigma * 7 + len(text))
+    k = fm.C.shape[0]
+    c = jnp.asarray(np.repeat(np.arange(k), 8), jnp.int32)
+    i = jnp.asarray(rng.integers(0, fm.m + 1, c.shape[0]), jnp.int32)
+    got = _lf_base(fm)[c] + wm_descend(fm.wm, c, i)
+    want = fm.C[c] + wm_rank(fm.wm, c, i)
+    assert np.array_equal(np.asarray(got), np.asarray(want))
+    j = jnp.arange(fm.m, dtype=jnp.int32)
+    sym, end = wm_access_descend(fm.wm, j)
+    assert np.array_equal(np.asarray(end),
+                          np.asarray(wm_descend(fm.wm, sym, j)))
+
+
+@pytest.mark.parametrize("sigma", [4, 256])
+def test_fm_count_fires_backward_step_counter(sigma):
+    """One trace of backward search advertises 2·nbits rank1 per step
+    (one descent per range end); a second call of the jitted count hits
+    the cache and fires nothing."""
+    text = np.arange(200) % sigma
+    fm = build_fm_index(jnp.asarray(text, jnp.int32), sigma, sample_rate=8)
+    pats = jnp.asarray(text[:12].reshape(3, 4), jnp.int32)
+    lens = jnp.asarray([4, 2, 1], jnp.int32)
+    obs.REGISTRY.reset()
+    f = jax.jit(fm_count)
+    jax.block_until_ready(f(fm, pats, lens))
+    jax.block_until_ready(f(fm, pats, lens))
+    snap = {k: v for k, v in obs.REGISTRY.snapshot()["counters"].items()
+            if k.startswith("index.backward_step")}
+    nbits = fm.wm.nbits
+    assert snap == {
+        f"index.backward_step{{rank1_per_step={2 * nbits}}}": 1}
 
 
 # ---------------------------------------------------------------------------
@@ -228,15 +305,48 @@ def test_sharded_locate_positions_are_real_matches():
 
 def test_sharded_pad_symbol_never_matches_padding():
     """Out-of-vocab query symbols (σ included — the tail-shard pad value)
-    must count 0 and locate nothing, not the padding run."""
+    must count 0 and locate nothing, not the padding run. The edges of
+    backward search against naive counts on the raw text: zero-length
+    patterns, lengths 1 and seam_overlap + 1, and ranges that empty at
+    the first step (out-of-vocab or absent last symbol)."""
     sigma = 7
     toks = np.arange(100) % sigma
+    toks[40] = 5                        # "5 5" occurs once, "2 2" never
     idx = build_sharded_index(toks, sigma, shard_bits=6, sample_rate=8)
+    assert idx.num_shards == 2          # 36 tokens + 28 padding in shard 1
     pats = jnp.asarray([[sigma, 0], [sigma + 3, 0], [-1, 0]], jnp.int32)
     lens = jnp.asarray([1, 2, 1], jnp.int32)
     assert np.asarray(idx.count(pats, lens)).tolist() == [0, 0, 0]
     pos = np.asarray(idx.locate(pats, lens, max_hits_per_shard=4))
     assert (pos == -1).all()
+
+    L = idx.seam_overlap + 1
+    rows, plens = [], []
+
+    def add(p, plen):
+        rows.append(list(p) + [sigma] * (L - len(p)))
+        plens.append(plen)
+    add([], 0)                          # zero-length: counts 0
+    add([3, 4], 0)                      # zero length, symbols past it
+    for c in range(sigma):
+        add([c], 1)
+    add(toks[56:56 + L], L)             # crosses the seam at 64
+    add(toks[2:2 + L], L)               # within shard 0
+    add(toks[70:70 + L], L)             # within the padded shard
+    add([1, 2, sigma], 3)               # out-of-vocab last symbol
+    add([1, 2, sigma + 9], 3)
+    add([3, -5], 2)
+    add([2, 2], 2)                      # absent at the first step
+    add([1, 0], 2)                      # absent at the second step
+    add([5, 5], 2)
+    add([sigma, 0, 1], 3)               # out-of-vocab first symbol
+    got = np.asarray(idx.count(jnp.asarray(rows, jnp.int32),
+                               jnp.asarray(plens, jnp.int32)))
+    want = [_naive_count(toks, np.asarray(r), l)
+            for r, l in zip(rows, plens)]
+    assert got.tolist() == want
+    assert want[2:2 + sigma] == np.bincount(toks, minlength=sigma).tolist()
+    assert min(want[2 + sigma:5 + sigma]) >= 1
 
 
 def test_sharded_tiny_and_padded_shard():
